@@ -30,6 +30,8 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.distance import sq_l2
+
 INVALID = jnp.int32(-1)
 INF = jnp.float32(jnp.inf)
 
@@ -62,8 +64,7 @@ def l2_dist_fn(vectors: jax.Array) -> Callable[[jax.Array, jax.Array], jax.Array
 
     def dist(q: jax.Array, ids: jax.Array) -> jax.Array:
         x = vectors[jnp.maximum(ids, 0)]
-        d = jnp.sum(jnp.square(x - q[None, :]), axis=-1)
-        return jnp.where(ids < 0, INF, d)
+        return jnp.where(ids < 0, INF, sq_l2(x, q[None, :]))
 
     return dist
 
@@ -187,7 +188,10 @@ def beam_search(
         active = jnp.any((ids >= 0) & ~exp)
         sel = jnp.argmin(jnp.where(exp | (ids < 0), INF, dists))
         node = ids[sel]
-        exp2 = exp.at[sel].set(True)
+        # a mask, not a scatter: on a TPU at 4096 lanes the vmapped
+        # scatter lost writes, and lanes re-expanded one node until
+        # max_iters
+        exp2 = exp | (jnp.arange(l) == sel)
         nbrs = jnp.where(node < 0, INVALID, adjacency[jnp.maximum(node, 0)])
         nd = dist_fn(q, nbrs)
         nd = jnp.where(nbrs < 0, INF, nd)
@@ -226,7 +230,7 @@ def beam_search(
         sel = jnp.argmin(
             jnp.where(s.expanded | (s.ids < 0), INF, s.dists), axis=1)
         node = jnp.take_along_axis(s.ids, sel[:, None], axis=1)[:, 0]
-        exp2 = s.expanded.at[lane_idx, sel].set(True)
+        exp2 = s.expanded | (jnp.arange(l)[None, :] == sel[:, None])
         nbrs = jnp.where(((node < 0) | ~active)[:, None], INVALID,
                          adjacency[jnp.maximum(node, 0)])         # (B, R)
         nids, ndsts, nexp, nfresh = dist_fn.hop_batch(

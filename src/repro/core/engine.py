@@ -112,22 +112,32 @@ class DiskStore:
         self.block_store.close()
 
 
+_BRUTE_CHUNK_ELEMS = 1 << 24    # floats per brute-force temporary
+
+
 def brute_force_knn(vectors: np.ndarray, queries: np.ndarray, k: int,
                     labels: np.ndarray | None = None,
                     filter_labels: np.ndarray | None = None,
                     exclude: np.ndarray | None = None) -> np.ndarray:
-    """Exact ground truth (chunked to bound memory)."""
+    """Exact ground truth.
+
+    Query rows are scored in chunks that bound each temporary to
+    ``_BRUTE_CHUNK_ELEMS`` floats (at most 256 rows): at deployment
+    width a 256-row chunk would need gigabytes.  Each row's distances
+    and ordering do not depend on the chunking."""
+    n, d = vectors.shape
+    rows = int(np.clip(_BRUTE_CHUNK_ELEMS // max(n * d, 1), 1, 256))
     out = np.zeros((queries.shape[0], k), np.int32)
-    for lo in range(0, queries.shape[0], 256):
-        q = queries[lo: lo + 256]
-        d = ((q[:, None, :] - vectors[None, :, :]) ** 2).sum(-1)
+    for lo in range(0, queries.shape[0], rows):
+        q = queries[lo: lo + rows]
+        dist = ((q[:, None, :] - vectors[None, :, :]) ** 2).sum(-1)
         if exclude is not None:
-            d[:, exclude] = np.inf
+            dist[:, exclude] = np.inf
         if filter_labels is not None and labels is not None:
-            fl = filter_labels[lo: lo + 256]
+            fl = filter_labels[lo: lo + rows]
             mism = (labels[None, :] != fl[:, None]) & (fl[:, None] >= 0)
-            d[mism] = np.inf
-        out[lo: lo + 256] = np.argsort(d, axis=1)[:, :k]
+            dist[mism] = np.inf
+        out[lo: lo + rows] = np.argsort(dist, axis=1)[:, :k]
     return out
 
 

@@ -16,6 +16,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.distance import ordered_sum
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -69,7 +71,10 @@ def query_lut(cb: PQCodebook, q: jax.Array) -> jax.Array:
     """Per-query ADC lookup table: (M, K) of squared subspace distances."""
     m, k, ds = cb.centroids.shape
     qs = q.reshape(m, ds)
-    return jnp.sum((cb.centroids - qs[:, None, :]) ** 2, axis=-1)
+    d2 = jnp.square(cb.centroids - qs[:, None, :])     # (M, K, ds)
+    # coordinate by coordinate: one order inside and outside the search
+    # loop (the unfused and fused hops build their LUTs in each)
+    return ordered_sum(d2[..., i] for i in range(ds))
 
 
 def adc_dist_fn(cb: PQCodebook, codes: jax.Array):
@@ -78,7 +83,9 @@ def adc_dist_fn(cb: PQCodebook, codes: jax.Array):
     def dist(q: jax.Array, ids: jax.Array) -> jax.Array:
         lut = query_lut(cb, q)                          # (M, K)
         c = codes[jnp.maximum(ids, 0)]                  # (m_ids, M)
-        d = jnp.take_along_axis(lut[None], c[:, :, None], axis=2)[:, :, 0].sum(-1)
+        g = jnp.take_along_axis(lut[None], c[:, :, None], axis=2)[:, :, 0]
+        # subspace by subspace, as the fused PQ hop kernel sums
+        d = ordered_sum(g[:, j] for j in range(g.shape[1]))
         return jnp.where(ids < 0, jnp.inf, d)
 
     return dist

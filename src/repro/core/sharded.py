@@ -33,10 +33,6 @@ from repro.core import lsh as lsh_mod
 from repro.core.beam_search import SearchSpec, beam_search, l2_dist_fn
 
 
-from repro.compat import mesh_context, shard_map_compat  # noqa: F401  (re-export:
-# the mesh-engine callers import these alongside the merge helpers below)
-
-
 # ---------------------------------------------------------------------------
 # Scatter-gather primitives — shared by the shard_map RAM path below and
 # the disk-backed scatter-gather engine (repro.store.sharded_store), so
@@ -145,8 +141,8 @@ def make_sharded_search(mesh, spec: SearchSpec, n_per_shard: int,
     out_specs = (P(all_axes, None), P(all_axes, None), P(all_axes),
                  P(qaxes, None), P(qaxes, None))
 
-    smapped = shard_map_compat(local_step, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs)
+    smapped = jax.shard_map(local_step, mesh=mesh, in_specs=in_specs,
+                            out_specs=out_specs, check_vma=False)
 
     def step(state: ShardedEngineState, queries):
         b_ids, b_stamp, b_step, ids, dists = smapped(

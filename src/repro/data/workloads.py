@@ -179,3 +179,38 @@ WORKLOADS = {
     "uniform": make_uniform,
     "papers": make_papers,
 }
+
+
+# Per-dimension scale of the noise ``lift`` adds.  Summed over 768
+# dimensions it carries about 768 * 0.02**2 = 0.31 of squared norm,
+# small against the ~24 of a point's intrinsic spread, so near
+# neighbors stay near while no ambient direction is left empty.
+_LIFT_NOISE = 0.02
+
+
+def lift(wl: Workload, dim: int, *, seed: int = 0) -> Workload:
+    """Embed a workload in ``dim`` ambient dimensions.
+
+    Real text embeddings are hundreds of dimensions wide but concentrate
+    on a low-dimensional manifold (the module note above).  A fixed
+    random map with orthonormal columns carries corpus and queries from
+    the generator's intrinsic dimension into ``dim`` without changing
+    any distance; isotropic Gaussian noise of scale ``_LIFT_NOISE`` then
+    fills the ambient space, as the residual of an embedding model
+    does.  Distances between points change by about the same amount
+    everywhere, so the neighbor structure the workload tests survives
+    and the graph stays navigable at deployment width."""
+    d = wl.corpus.shape[1]
+    if dim < d:
+        raise ValueError(f"cannot lift {d}-d data into {dim} dimensions")
+    rng = np.random.default_rng(seed)
+    basis, _ = np.linalg.qr(rng.normal(size=(dim, d)))     # (dim, d)
+    basis = basis.T.astype(np.float32)
+
+    def up(x: np.ndarray) -> np.ndarray:
+        y = x @ basis
+        y += _LIFT_NOISE * rng.standard_normal(y.shape, np.float32)
+        return y
+
+    return dataclasses.replace(wl, corpus=up(wl.corpus),
+                               queries=up(wl.queries))

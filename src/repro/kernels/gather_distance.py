@@ -1,17 +1,15 @@
-"""Scalar-prefetch gather + distance — DiskANN's SSD read, TPU-native.
+"""Row gather + distance — DiskANN's SSD read, TPU-native.
 
 DiskANN's inner loop reads a node's neighbor vectors from SSD and
 overlaps the read with distance computation on the previous node.  The
-TPU analogue keeps the vector table in HBM and uses
-``PrefetchScalarGridSpec``: the neighbor ids arrive in SMEM *before* the
-grid runs, so the BlockSpec ``index_map`` can dereference them and the
-Pallas pipeline streams each gathered row HBM->VMEM while the previous
-row's distance is computed — the same latency-hiding structure, one
-memory level up (DESIGN.md §3).
+TPU analogue keeps the vector table in HBM and gathers with in-kernel
+async copies: each grid step takes a block of ids as an SMEM block, puts
+one row copy per id in flight (HBM -> VMEM, the same gather
+``fused_hop`` uses), and computes the block's squared distances against
+the VMEM-resident query once the copies land.
 
-Grid = one step per candidate id; each step fetches one (1, d) row and
-emits one squared distance against the VMEM-resident query.  Invalid ids
-(< 0, adjacency padding) fetch row 0 and are masked to +inf.
+Invalid ids (< 0, adjacency padding) fetch row 0 and are masked to +inf;
+a block of only invalid ids issues no copy.
 """
 from __future__ import annotations
 
@@ -22,35 +20,47 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.distance import sq_l2
+from repro.kernels.fused_hop import gather_rows, row_table
 
-def _gather_kernel(ids_ref, x_ref, q_ref, o_ref):
-    i = pl.program_id(0)
-    x = x_ref[...].astype(jnp.float32)      # (1, d) gathered row
-    q = q_ref[...].astype(jnp.float32)      # (1, d) query (replicated)
-    d = jnp.sum(jnp.square(x - q))
-    o_ref[0] = jnp.where(ids_ref[i] < 0, jnp.inf, d)
+MAX_BLOCK = 128   # ids per grid step
+
+
+def _gather_kernel(ids_smem, ids_ref, q_ref, rows_ref, o_ref, xs_ref, sem,
+                   *, block):
+    gather_rows(ids_smem, rows_ref, xs_ref, sem, tile=1, c=block,
+                first=pl.program_id(0))
+    # row and query zero-padded to W' lanes: the same sum as sq_l2 of
+    # the unpadded ones
+    d = sq_l2(xs_ref[...][0, :, 0, :], q_ref[...])[:, None]   # (block, 1)
+    o_ref[...] = jnp.where(ids_ref[...] < 0, jnp.inf, d)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def gather_distance(vectors: jax.Array, ids: jax.Array, query: jax.Array, *,
                     interpret: bool = False) -> jax.Array:
     """(N, d) table, (M,) int32 ids, (d,) query -> (M,) squared distances."""
-    n, d = vectors.shape
+    d = vectors.shape[1]
     m = ids.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(m,),
+    block = min(MAX_BLOCK, -(-m // 8) * 8)
+    mp = -(-m // block) * block
+    ids_p = jnp.pad(ids, (0, mp - m), constant_values=-1)
+    rows = row_table(vectors)
+    query = jnp.pad(query, (0, rows.shape[-1] - d))
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, block=block),
+        grid=(mp // block,),
         in_specs=[
-            # the gathered row: block index comes from the prefetched ids
-            pl.BlockSpec((1, d), lambda i, ids_ref: (jnp.maximum(ids_ref[i], 0), 0)),
-            # the query, same block every step
-            pl.BlockSpec((1, d), lambda i, ids_ref: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),       # DMA addresses
+            pl.BlockSpec((block, 1), lambda i: (i, 0)),  # ids, for the mask
+            pl.BlockSpec((1, query.shape[0]), lambda i: (0, 0)),  # query
+            pl.BlockSpec(memory_space=pl.ANY),           # row table
         ],
-        out_specs=pl.BlockSpec((1,), lambda i, ids_ref: (i,)),
-    )
-    return pl.pallas_call(
-        _gather_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.float32),
+        out_specs=pl.BlockSpec((block, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((mp, 1), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((1, block) + rows.shape[1:], rows.dtype),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(ids, vectors, query[None, :])
+    )(ids_p.reshape(mp // block, block), ids_p[:, None], query[None, :],
+      rows)
+    return out[:m, 0]

@@ -25,8 +25,11 @@ def _l2_kernel(q_ref, x_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)            # (bc, d)
     qn = jnp.sum(q * q, axis=1, keepdims=True)    # (bq, 1)
     xn = jnp.sum(x * x, axis=1, keepdims=True).T  # (1, bc)
+    # HIGHEST: f32 passes on the MXU; the default truncates the operands
+    # to bf16, three digits short of an f32 distance
     cross = jax.lax.dot_general(
         q, x, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)       # (bq, bc) on the MXU
     o_ref[...] = qn + xn - 2.0 * cross
 

@@ -22,7 +22,7 @@ def _lsh_kernel(q_ref, h_ref, o_ref):
         preferred_element_type=jnp.float32)       # (bq, L)
     bits = (proj >= 0.0).astype(jnp.int32)
     weights = 2 ** jax.lax.broadcasted_iota(jnp.int32, proj.shape, 1)
-    o_ref[...] = jnp.sum(bits * weights, axis=1)
+    o_ref[...] = jnp.sum(bits * weights, axis=1, keepdims=True)  # (bq, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q", "interpret"))
@@ -39,7 +39,9 @@ def lsh_hash(queries: jax.Array, hyperplanes: jax.Array, *,
             pl.BlockSpec((block_q, d), lambda i: (i, 0)),
             pl.BlockSpec((l, d), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_q,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((b,), jnp.int32),
+        # codes leave as a (B, 1) column: the layout the chip tiles a
+        # per-row result in (a 1-D output is tiled by 1024, not by block)
+        out_specs=pl.BlockSpec((block_q, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, 1), jnp.int32),
         interpret=interpret,
-    )(queries, hyperplanes)
+    )(queries, hyperplanes)[:, 0]

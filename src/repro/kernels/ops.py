@@ -28,11 +28,8 @@ from repro.kernels.gather_distance import gather_distance as _gather_distance
 from repro.kernels.l2_distance import l2_distance as _l2_distance
 from repro.kernels.lsh_hash import lsh_hash as _lsh_hash
 from repro.kernels.pq_adc import pq_adc as _pq_adc
+from repro.kernels.platform import interpret_mode
 from repro.obs.profiler import annotate
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _pad_rows(x: jax.Array, mult: int, value=0) -> jax.Array:
@@ -52,7 +49,7 @@ def _l2_distance_jit(queries: jax.Array, points: jax.Array, *,
     qp = _pad_rows(queries, bq)
     pp = _pad_rows(points, bc)
     out = _l2_distance(qp, pp, block_q=bq, block_c=bc,
-                       interpret=not _on_tpu())
+                       interpret=interpret_mode())
     return out[:b, :c]
 
 
@@ -67,7 +64,7 @@ def l2_distance(queries: jax.Array, points: jax.Array, *,
 @jax.jit
 def _gather_distance_jit(vectors: jax.Array, ids: jax.Array,
                          query: jax.Array) -> jax.Array:
-    return _gather_distance(vectors, ids, query, interpret=not _on_tpu())
+    return _gather_distance(vectors, ids, query, interpret=interpret_mode())
 
 
 def gather_distance(vectors: jax.Array, ids: jax.Array,
@@ -83,7 +80,7 @@ def _lsh_hash_jit(queries: jax.Array, hyperplanes: jax.Array, *,
     b = queries.shape[0]
     bq = min(block_q, max(b, 8))
     qp = _pad_rows(queries, bq)
-    out = _lsh_hash(qp, hyperplanes, block_q=bq, interpret=not _on_tpu())
+    out = _lsh_hash(qp, hyperplanes, block_q=bq, interpret=interpret_mode())
     return out[:b]
 
 
@@ -100,7 +97,7 @@ def _pq_adc_jit(lut: jax.Array, codes: jax.Array, *,
     c = codes.shape[0]
     bc = min(block_c, max(c, 8))
     cp = _pad_rows(codes, bc)
-    out = _pq_adc(lut, cp, block_c=bc, interpret=not _on_tpu())
+    out = _pq_adc(lut, cp, block_c=bc, interpret=interpret_mode())
     return out[:c]
 
 
@@ -114,12 +111,12 @@ def fused_hop_l2(vectors, cand_ids, queries, beam_ids, beam_dists, beam_exp):
     """One fused L2 hop (gather + distance + beam merge) for a batch.
 
     (N, d) table, (B, C) candidate ids, (B, d) queries, (B, L) beam ->
-    (new_ids, new_dists, new_exp, n_fresh).  No padding: the kernel is
-    shape-polymorphic over B/C/L (grid is one step per lane).
+    (new_ids, new_dists, new_exp, n_fresh), for any B/C/L (the kernel
+    pads the batch to whole lane tiles itself).
     """
     with annotate("repro.kernels.fused_hop_l2"):
         return _fused_hop_l2(vectors, cand_ids, queries, beam_ids,
-                             beam_dists, beam_exp, interpret=not _on_tpu())
+                             beam_dists, beam_exp, interpret=interpret_mode())
 
 
 def fused_hop_pq(luts, codes, cand_ids, beam_ids, beam_dists, beam_exp):
@@ -127,7 +124,7 @@ def fused_hop_pq(luts, codes, cand_ids, beam_ids, beam_dists, beam_exp):
     (B, L) beam -> (new_ids, new_dists, new_exp, n_fresh)."""
     with annotate("repro.kernels.fused_hop_pq"):
         return _fused_hop_pq(luts, codes, cand_ids, beam_ids,
-                             beam_dists, beam_exp, interpret=not _on_tpu())
+                             beam_dists, beam_exp, interpret=interpret_mode())
 
 
 # re-export oracles for convenience in tests/benchmarks

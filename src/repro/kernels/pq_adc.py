@@ -8,7 +8,7 @@ LUT gather as a one-hot contraction on the MXU:
     dist[c] = sum_m LUT[m, code[c, m]]
             = sum_{m,k} onehot(code)[c, m, k] * LUT[m, k]
 
-The (bc, M*K) one-hot tile and the flattened (M*K,) LUT turn into a
+The (bc, M*K) one-hot tile and the flattened (M*K, 1) LUT turn into a
 single ``dot`` — gathers become a matmul, the canonical TPU adaptation
 (DESIGN.md §3).
 """
@@ -22,17 +22,21 @@ from jax.experimental import pallas as pl
 
 
 def _adc_kernel(lut_ref, codes_ref, o_ref, *, n_centroids: int):
-    lut = lut_ref[...].astype(jnp.float32)        # (M, K)
+    lut = lut_ref[...].astype(jnp.float32)        # (M*K, 1) flattened LUT
     codes = codes_ref[...]                        # (bc, M) int32
-    m, k = lut.shape
-    # per-subspace one-hot over centroids -> (bc, M, K), flattened so the
-    # whole gather-sum is a single (bc, M*K) @ (M*K,) MXU contraction.
-    onehot = (codes[:, :, None] ==
-              jax.lax.broadcasted_iota(jnp.int32, (1, 1, k), 2))
-    onehot = onehot.reshape(codes.shape[0], m * k).astype(jnp.float32)
+    m, k = codes.shape[1], n_centroids
+    # one-hot over (subspace, centroid), built directly in its flattened
+    # (bc, M*K) layout: column p holds subspace p // K, centroid p % K
+    col = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], m * k), 1)
+    onehot = jnp.zeros(col.shape, bool)
+    for j in range(m):
+        onehot = onehot | ((col // k == j)
+                           & (codes[:, j:j + 1] == col % k))
+    # HIGHEST: the LUT entries keep f32 precision on the MXU
     o_ref[...] = jax.lax.dot_general(
-        onehot, lut.reshape(m * k),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        onehot.astype(jnp.float32), lut,
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
@@ -46,10 +50,12 @@ def pq_adc(lut: jax.Array, codes: jax.Array, *, block_c: int = 128,
         functools.partial(_adc_kernel, n_centroids=k),
         grid=(c // block_c,),
         in_specs=[
-            pl.BlockSpec((m, k), lambda i: (0, 0)),
+            pl.BlockSpec((m * k, 1), lambda i: (0, 0)),
             pl.BlockSpec((block_c, m), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block_c,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((c,), jnp.float32),
+        # distances leave as a (C, 1) column: the layout the chip tiles
+        # a per-row result in (a 1-D output is tiled by 1024, not by block)
+        out_specs=pl.BlockSpec((block_c, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((c, 1), jnp.float32),
         interpret=interpret,
-    )(lut, codes)
+    )(lut.reshape(m * k, 1), codes)[:, 0]

@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.distance import sq_l2
+
 
 def l2_distance_ref(queries: jnp.ndarray, points: jnp.ndarray) -> jnp.ndarray:
     """(B, d), (C, d) -> (B, C) squared L2 distances."""
@@ -21,8 +23,7 @@ def gather_distance_ref(vectors: jnp.ndarray, ids: jnp.ndarray,
 
     Invalid ids (< 0) produce +inf, matching beam-search conventions.
     """
-    x = vectors[jnp.maximum(ids, 0)].astype(jnp.float32)
-    d = jnp.sum(jnp.square(x - query[None, :].astype(jnp.float32)), axis=-1)
+    d = sq_l2(vectors[jnp.maximum(ids, 0)], query[None, :])
     return jnp.where(ids < 0, jnp.inf, d)
 
 

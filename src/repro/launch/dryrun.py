@@ -35,7 +35,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ARCH_IDS, SHAPES, get_config
-from repro.compat import mesh_context
 from repro.launch import roofline as rl
 from repro.launch.mesh import batch_axes, make_production_mesh
 from repro.models import model as M
@@ -182,7 +181,7 @@ def run_cell(arch: str, shape: str, multi_pod: bool) -> dict:
                               "§Arch-applicability)"}
         fn, args, shardings, donate, mf, hbm = input_specs(cfg, shape, mesh)
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=shardings, donate_argnums=donate)
         lowered = jitted.lower(*args)
         hlo = lowered.as_text()

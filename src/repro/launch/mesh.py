@@ -16,9 +16,14 @@ import jax
 
 
 def make_production_mesh(*, multi_pod: bool = False):
+    """Axes are ``Auto``: the model code places arrays with sharding
+    constraints and leaves propagation to the compiler, so array types
+    carry no explicit sharding that every op would have to agree on
+    (``jax.make_mesh`` defaults to ``Explicit`` axes)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):

@@ -25,7 +25,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs.base import get_config, get_reduced
-from repro.compat import mesh_context
 from repro.data.pipeline import Prefetcher, TokenPipeline
 from repro.ft import checkpoint as ckpt
 from repro.ft.elastic import choose_mesh_shape, make_mesh_from_plan
@@ -69,7 +68,7 @@ def train(cfg, *, steps: int, global_batch: int, seq_len: int,
     batch_sh = {k: NamedSharding(mesh, P(ba)) for k in
                 ["tokens"] + list(extras)}
 
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         start_step = 0
         if resume and ckpt_dir and ckpt.latest_step(ckpt_dir) is not None:
             example = {

@@ -90,8 +90,7 @@ def maybe_shard(x, spec):
     model code runs under pjit on any production mesh and on the single
     bare CPU device in smoke tests.
     """
-    from repro.compat import active_abstract_mesh
-    mesh = active_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     if mesh.empty:
         return x
     names = set(mesh.axis_names)

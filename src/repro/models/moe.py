@@ -151,8 +151,7 @@ def moe_layer(params, x, cfg, *, mlp_kind="swiglu"):
     """
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
-    from repro.compat import active_abstract_mesh
-    mesh = active_abstract_mesh()
+    mesh = jax.sharding.get_abstract_mesh()
     use_smap = (not mesh.empty and "model" in mesh.axis_names
                 and e % mesh.shape["model"] == 0
                 and mesh.shape["model"] > 1)
@@ -195,9 +194,8 @@ def moe_layer(params, x, cfg, *, mlp_kind="swiglu"):
                    if "shared" in params else None)
     dense_spec = ({"wi": P(None, "model"), "wo": P("model", None)}
                   if "dense" in params else None)
-    from repro.compat import shard_map_compat
-    y, aux = shard_map_compat(
-        local, mesh=mesh,
+    y, aux = jax.shard_map(
+        local, mesh=mesh, check_vma=False,
         in_specs=(pspec["router"], pspec["wi"], pspec["wo"], shared_spec,
                   dense_spec, P(ba, None, None)),
         out_specs=(P(ba, None, None), P()),

@@ -84,3 +84,23 @@ def test_pq_engine_recall_with_rerank(corpus, queries, ground_truth):
                              pq_subspaces=4).build(corpus[0])
     ids, _, _ = eng.search(queries, k=10, beam_width=32)
     assert recall_at_k(ids, ground_truth) > 0.8
+
+
+def test_brute_force_chunking_does_not_change_results(monkeypatch):
+    """Chunked exact kNN equals one plain pass over all query rows, with
+    the filter and exclusion masks applied."""
+    from repro.core import engine
+    rng = np.random.default_rng(4)
+    v = rng.normal(size=(300, 12)).astype(np.float32)
+    q = rng.normal(size=(50, 12)).astype(np.float32)
+    labels = rng.integers(0, 3, 300).astype(np.int32)
+    fl = rng.integers(-1, 3, 50).astype(np.int32)
+    excl = np.array([1, 5, 7])
+    d = ((q[:, None, :] - v[None, :, :]) ** 2).sum(-1)
+    d[:, excl] = np.inf
+    d[(labels[None, :] != fl[:, None]) & (fl[:, None] >= 0)] = np.inf
+    want = np.argsort(d, axis=1)[:, :5]
+    monkeypatch.setattr(engine, "_BRUTE_CHUNK_ELEMS", 300 * 12 * 3)
+    got = engine.brute_force_knn(v, q, 5, labels=labels, filter_labels=fl,
+                                 exclude=excl)
+    np.testing.assert_array_equal(got, want)

@@ -25,6 +25,18 @@ def test_l2_distance(b, c, d, dtype):
     np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
 
 
+@pytest.mark.parametrize("d", [1, 16, 24, 100, 128, 200, 700])
+def test_lane_sum_ignores_zero_padding(d):
+    """The kernels sum rows zero-padded to whole 128-lane tiles, XLA the
+    unpadded rows: both must give the same float, and the sum itself."""
+    from repro.distance import lane_sum
+    s = np.random.default_rng(d).random((32, d)).astype(np.float32)
+    got = np.asarray(lane_sum(jnp.asarray(s)))
+    padded = jnp.pad(jnp.asarray(s), ((0, 0), (0, (-d) % 128)))
+    np.testing.assert_array_equal(np.asarray(lane_sum(padded)), got)
+    np.testing.assert_allclose(got, s.astype(np.float64).sum(-1), rtol=1e-6)
+
+
 @pytest.mark.parametrize("n,m,d", [(50, 8, 16), (500, 33, 64), (1000, 64, 128)])
 def test_gather_distance(n, m, d):
     x = _arr((n, d))
@@ -52,6 +64,15 @@ def test_pq_adc(m, k, c):
     got = ops.pq_adc(lut, codes)
     want = ref.pq_adc_ref(lut, codes)
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+
+
+def test_interpret_mode_only_off_tpu(monkeypatch):
+    """A TPU backend always gets compiled kernels, never the interpreter."""
+    from repro.kernels import platform
+    monkeypatch.setattr(platform.jax, "default_backend", lambda: "tpu")
+    assert platform.interpret_mode() is False
+    monkeypatch.setattr(platform.jax, "default_backend", lambda: "cpu")
+    assert platform.interpret_mode() is True
 
 
 def test_l2_distance_agrees_with_beam_search_metric():

@@ -19,8 +19,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core.beam_search import SearchSpec
-from repro.core.sharded import (build_sharded_state, make_sharded_search,
-                                mesh_context)
+from repro.core.sharded import build_sharded_state, make_sharded_search
 from repro.core import brute_force_knn, recall_at_k
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
@@ -35,7 +34,7 @@ step = make_sharded_search(mesh, spec, 400, 4)
 
 q = (centers[rng.integers(0, 16, 64)]
      + 0.3 * rng.normal(size=(64, 24))).astype(np.float32)
-with mesh_context(mesh):
+with jax.set_mesh(mesh):
     jq = jax.device_put(jnp.asarray(q), NamedSharding(mesh, P("data", None)))
     st = state
     for rep in range(3):     # repeats exercise the per-device catapults

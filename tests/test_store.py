@@ -151,6 +151,17 @@ def test_fetch_batch_dedup_beats_naive_under_pressure(tmp_path):
 
 # ---------------------------------------------------------------- disk engine
 
+@pytest.mark.parametrize("dim,m", [(768, 192), (128, 32), (36, 9),
+                                   (24, 8), (16, 8), (12, 4), (6, 2),
+                                   (7, 1)])
+def test_default_pq_subspaces(dim, m):
+    """About 4 dimensions per subspace at embedding widths, and the
+    largest of {8, 4, 2} below 36 dimensions."""
+    from repro.store.io_engine import default_pq_subspaces
+    assert default_pq_subspaces(dim) == m
+    assert dim % m == 0
+
+
 def test_disk_engine_recall_parity_with_ram(tmp_store_dir, corpus, queries,
                                             ground_truth, prebuilt):
     """Acceptance: ±0.01 recall@10 vs the in-RAM engine, same graph."""

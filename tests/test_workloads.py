@@ -74,3 +74,20 @@ def test_corpora_are_navigable(maker):
     # 0.8 at this deliberately small scale (3k pts, beam 16); the broken
     # regime this guards against measures ~0.0 (see module docstring)
     assert hit > 0.8, f"self-recall {hit}: corpus not navigable"
+
+
+def test_lift_keeps_the_neighbor_structure():
+    """Lifting to deployment width keeps who is whose neighbor: the map
+    is orthonormal and the ambient noise is small."""
+    from repro.core import brute_force_knn, recall_at_k
+    wl = W.make_medrag_zipf(n=600, n_queries=64)
+    up = W.lift(wl, 768, seed=5)
+    assert up.corpus.shape == (600, 768) and up.queries.shape == (64, 768)
+    assert up.corpus.dtype == np.float32
+    truth = brute_force_knn(wl.corpus, wl.queries, 10)
+    assert recall_at_k(brute_force_knn(up.corpus, up.queries, 10),
+                       truth) > 0.9
+    np.testing.assert_array_equal(W.lift(wl, 768, seed=5).corpus,
+                                  up.corpus)
+    with pytest.raises(ValueError):
+        W.lift(wl, 8)
