@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.adapt import policy as pol
 from repro.adapt import stats as ts
+from repro.obs.profiler import span
 
 HISTORY_LIMIT = 1024
 
@@ -138,7 +139,7 @@ class CatapultMaintainer:
         ``SearchStats`` the search returned; ``real_mask``: (B,) bool,
         False on padded lanes (None = all real).
         """
-        with self._lock:
+        with span("catapultdb.adapt.observe"), self._lock:
             if not self._gate_on and not self._probing and not self._shadow:
                 # gated off: one counter, occasionally arm a probe
                 self._off_batches += 1
@@ -185,7 +186,8 @@ class CatapultMaintainer:
             self._since_tick += 1
             if self.tick_every and self._since_tick >= self.tick_every:
                 self._since_tick = 0
-                self._tick_locked()
+                with span("catapultdb.adapt.tick"):
+                    self._tick_locked()
 
     def _fold(self, queries, stats, real_mask, baseline: bool) -> None:
         b = int(np.shape(queries)[0])
@@ -213,7 +215,7 @@ class CatapultMaintainer:
     def tick(self) -> None:
         """Run one maintenance pass now (the background thread's body;
         also callable directly, e.g. after a bulk load)."""
-        with self._lock:
+        with span("catapultdb.adapt.tick"), self._lock:
             self._tick_locked()
 
     def _tick_locked(self) -> None:

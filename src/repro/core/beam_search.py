@@ -63,7 +63,8 @@ def l2_dist_fn(vectors: jax.Array) -> Callable[[jax.Array, jax.Array], jax.Array
     """Default distance: full-precision squared L2 against a vector table."""
 
     def dist(q: jax.Array, ids: jax.Array) -> jax.Array:
-        x = vectors[jnp.maximum(ids, 0)]
+        with jax.named_scope("hop/gather"):
+            x = vectors[jnp.maximum(ids, 0)]
         return jnp.where(ids < 0, INF, sq_l2(x, q[None, :]))
 
     return dist
@@ -185,28 +186,33 @@ def beam_search(
 
     def lane_step(q, lane, ids, dists, exp, hops, ndists, trace_row,
                   scored_row, it):
-        active = jnp.any((ids >= 0) & ~exp)
-        sel = jnp.argmin(jnp.where(exp | (ids < 0), INF, dists))
-        node = ids[sel]
-        # a mask, not a scatter: on a TPU at 4096 lanes the vmapped
-        # scatter lost writes, and lanes re-expanded one node until
-        # max_iters
-        exp2 = exp | (jnp.arange(l) == sel)
-        nbrs = jnp.where(node < 0, INVALID, adjacency[jnp.maximum(node, 0)])
-        nd = dist_fn(q, nbrs)
-        nd = jnp.where(nbrs < 0, INF, nd)
-        if neighbor_mask_fn is not None:
-            nd = jnp.where(neighbor_mask_fn(lane, nbrs), nd, INF)
-        nids, ndsts, nexp, nfresh = _merge(ids, dists, exp2, nbrs, nd)
-        ids = jnp.where(active, nids, ids)
-        dists = jnp.where(active, ndsts, dists)
-        exp = jnp.where(active, nexp, exp)
-        hops = hops + active.astype(jnp.int32)
-        ndists = ndists + jnp.where(active, nfresh, 0)
-        trace_row = trace_row.at[it].set(jnp.where(active, node, INVALID))
-        if spec.record_scored:
-            scored_row = scored_row.at[it].set(
-                jnp.where(active, nbrs, INVALID))
+        with jax.named_scope("hop/gather"):
+            active = jnp.any((ids >= 0) & ~exp)
+            sel = jnp.argmin(jnp.where(exp | (ids < 0), INF, dists))
+            node = ids[sel]
+            # a mask, not a scatter: on a TPU at 4096 lanes the vmapped
+            # scatter lost writes, and lanes re-expanded one node until
+            # max_iters
+            exp2 = exp | (jnp.arange(l) == sel)
+            nbrs = jnp.where(node < 0, INVALID,
+                             adjacency[jnp.maximum(node, 0)])
+        with jax.named_scope("hop/distance"):
+            nd = dist_fn(q, nbrs)
+            nd = jnp.where(nbrs < 0, INF, nd)
+            if neighbor_mask_fn is not None:
+                nd = jnp.where(neighbor_mask_fn(lane, nbrs), nd, INF)
+        with jax.named_scope("hop/merge"):
+            nids, ndsts, nexp, nfresh = _merge(ids, dists, exp2, nbrs, nd)
+            ids = jnp.where(active, nids, ids)
+            dists = jnp.where(active, ndsts, dists)
+            exp = jnp.where(active, nexp, exp)
+            hops = hops + active.astype(jnp.int32)
+            ndists = ndists + jnp.where(active, nfresh, 0)
+            trace_row = trace_row.at[it].set(
+                jnp.where(active, node, INVALID))
+            if spec.record_scored:
+                scored_row = scored_row.at[it].set(
+                    jnp.where(active, nbrs, INVALID))
         return ids, dists, exp, hops, ndists, trace_row, scored_row
 
     def cond(s: BeamState):
@@ -226,25 +232,32 @@ def beam_search(
         # B lanes is one kernel dispatch.  Converged lanes feed all-(-1)
         # neighbor rows (the kernel skips their DMAs) and their outputs
         # are discarded below, exactly like the composed path.
-        active = jnp.any((s.ids >= 0) & ~s.expanded, axis=1)        # (B,)
-        sel = jnp.argmin(
-            jnp.where(s.expanded | (s.ids < 0), INF, s.dists), axis=1)
-        node = jnp.take_along_axis(s.ids, sel[:, None], axis=1)[:, 0]
-        exp2 = s.expanded | (jnp.arange(l)[None, :] == sel[:, None])
-        nbrs = jnp.where(((node < 0) | ~active)[:, None], INVALID,
-                         adjacency[jnp.maximum(node, 0)])         # (B, R)
-        nids, ndsts, nexp, nfresh = dist_fn.hop_batch(
-            queries, nbrs, s.ids, s.dists, exp2)
-        act = active[:, None]
-        ids = jnp.where(act, nids, s.ids)
-        dists = jnp.where(act, ndsts, s.dists)
-        exp = jnp.where(act, nexp, s.expanded)
-        hops = s.hops + active.astype(jnp.int32)
-        ndists = s.ndists + jnp.where(active, nfresh, 0)
-        trace = s.trace.at[:, s.it].set(jnp.where(active, node, INVALID))
-        scored = s.scored
-        if spec.record_scored:
-            scored = scored.at[:, s.it].set(jnp.where(act, nbrs, INVALID))
+        # The kernel gathers, scores and merges in one dispatch: its
+        # device time counts under hop/distance.
+        with jax.named_scope("hop/gather"):
+            active = jnp.any((s.ids >= 0) & ~s.expanded, axis=1)    # (B,)
+            sel = jnp.argmin(
+                jnp.where(s.expanded | (s.ids < 0), INF, s.dists), axis=1)
+            node = jnp.take_along_axis(s.ids, sel[:, None], axis=1)[:, 0]
+            exp2 = s.expanded | (jnp.arange(l)[None, :] == sel[:, None])
+            nbrs = jnp.where(((node < 0) | ~active)[:, None], INVALID,
+                             adjacency[jnp.maximum(node, 0)])     # (B, R)
+        with jax.named_scope("hop/distance"):
+            nids, ndsts, nexp, nfresh = dist_fn.hop_batch(
+                queries, nbrs, s.ids, s.dists, exp2)
+        with jax.named_scope("hop/merge"):
+            act = active[:, None]
+            ids = jnp.where(act, nids, s.ids)
+            dists = jnp.where(act, ndsts, s.dists)
+            exp = jnp.where(act, nexp, s.expanded)
+            hops = s.hops + active.astype(jnp.int32)
+            ndists = s.ndists + jnp.where(active, nfresh, 0)
+            trace = s.trace.at[:, s.it].set(
+                jnp.where(active, node, INVALID))
+            scored = s.scored
+            if spec.record_scored:
+                scored = scored.at[:, s.it].set(
+                    jnp.where(act, nbrs, INVALID))
         return BeamState(ids, dists, exp, hops, ndists, trace, scored,
                          s.it + 1)
 

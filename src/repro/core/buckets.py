@@ -59,7 +59,8 @@ def lookup(state: BucketState, bucket_idx: jax.Array) -> tuple[jax.Array, jax.Ar
     Returns (ids (B, b), tags (B, b)).  Pure gather — the read-locked
     critical section of the paper costs one HBM fetch here.
     """
-    return state.ids[bucket_idx], state.tag[bucket_idx]
+    with jax.named_scope("catapult/lookup"):
+        return state.ids[bucket_idx], state.tag[bucket_idx]
 
 
 @jax.jit
@@ -89,8 +90,10 @@ def publish(state: BucketState, bucket_idx: jax.Array, dest: jax.Array,
         return (ids.at[h].set(row_ids), stamp.at[h].set(row_stamp),
                 tag.at[h].set(row_tag), step + do.astype(jnp.int32))
 
-    ids, stamp, tag, step = jax.lax.fori_loop(
-        0, bucket_idx.shape[0], one, (state.ids, state.stamp, state.tag, state.step))
+    with jax.named_scope("catapult/publish"):
+        ids, stamp, tag, step = jax.lax.fori_loop(
+            0, bucket_idx.shape[0], one,
+            (state.ids, state.stamp, state.tag, state.step))
     return BucketState(ids=ids, stamp=stamp, tag=tag, step=step)
 
 

@@ -73,7 +73,8 @@ def catapulted_lookup(
 ) -> tuple[CatapultState, SearchResult, CatapultStats]:
     """One batch of Algorithm 2.  Returns (new state, results, stats)."""
     b = queries.shape[0]
-    hashes = lsh_mod.hash_codes(state.lsh, queries)          # (B,)
+    with jax.named_scope("catapult/lsh"):
+        hashes = lsh_mod.hash_codes(state.lsh, queries)      # (B,)
     cat_ids, cat_tags = bk.lookup(state.buckets, hashes)     # (B, cap)
 
     if filter_labels is None:
@@ -102,11 +103,15 @@ def catapulted_lookup(
                          neighbor_mask_fn=neighbor_mask_fn,
                          result_mask_fn=result_mask_fn)
 
-    used = jnp.any(cat_sp >= 0, axis=1)
-    # "won": some catapult start is strictly closer to q than the fallback.
-    d_start = jax.vmap(dist_fn)(queries, cat_sp)
-    d_fb = jax.vmap(lambda q, m: dist_fn(q, m[None]))(queries, fallback)[:, 0]
-    won = used & (jnp.min(jnp.where(cat_sp >= 0, d_start, jnp.inf), axis=1) < d_fb)
+    with jax.named_scope("catapult/lookup"):
+        used = jnp.any(cat_sp >= 0, axis=1)
+        # "won": some catapult start is strictly closer to q than the
+        # fallback.
+        d_start = jax.vmap(dist_fn)(queries, cat_sp)
+        d_fb = jax.vmap(lambda q, m: dist_fn(q, m[None]))(
+            queries, fallback)[:, 0]
+        won = used & (jnp.min(jnp.where(cat_sp >= 0, d_start, jnp.inf),
+                              axis=1) < d_fb)
 
     # Masked lanes (batch padding, frozen replicas) neither publish nor
     # report usage: a padded lane repeats a real query, so letting it

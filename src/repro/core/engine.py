@@ -25,7 +25,6 @@ the host keeps numpy mirrors for graph surgery (build/insert).
 from __future__ import annotations
 
 import dataclasses
-from contextlib import nullcontext
 from functools import partial
 from typing import NamedTuple, Optional
 
@@ -42,6 +41,8 @@ from repro.core import pq as pq_mod
 from repro.core.beam_search import (SearchSpec, beam_search, beam_search_l2,
                                     l2_dist_fn)
 from repro.core.vamana import VamanaParams, build_vamana, medoid_index
+from repro.obs.profiler import span
+from repro.obs.trace import stages
 
 
 class SearchStats(NamedTuple):
@@ -340,7 +341,8 @@ class VectorSearchEngine:
         supplied, the route/rerank stages are timed into it (each stage
         syncs the device, so pass one only on explain queries).
         """
-        queries = jnp.asarray(queries, jnp.float32)
+        with span("catapultdb.engine.upload"):
+            queries = jnp.asarray(queries, jnp.float32)
         b = queries.shape[0]
         l = beam_width or max(2 * k, 16)
         # PQ mode reranks the *entire* final beam at full precision
@@ -357,21 +359,26 @@ class VectorSearchEngine:
                    if filter_labels is not None
                    else jnp.full((b,), -1, jnp.int32))
 
-        stage = trace.stage if trace is not None else (lambda _: nullcontext())
-        with stage("route"):
+        stage = stages(trace)
+        with stage("route", "catapultdb.engine.route"):
             res, used, won = self._dispatch(queries, flabels, spec,
                                             publish_mask=publish_mask)
             if trace is not None:
                 jax.block_until_ready(res.ids)
 
-        with stage("rerank"):
+        # explain's "rerank" stage is the whole readback: the PQ rerank
+        # runs between the beam's and the counts' host reads
+        with stage("rerank", "catapultdb.engine.readback"):
+            used, won = np.asarray(used), np.asarray(won)
             ids, dists = np.asarray(res.ids), np.asarray(res.dists)
             if self.pq_subspaces:  # full-precision rerank (DiskANN final fetch)
-                rr = jax.vmap(partial(pq_mod.rerank, self._vec, k=k))(
-                    queries, res.ids)
-                ids, dists = np.asarray(rr[0]), np.asarray(rr[1])
-        stats = SearchStats(hops=np.asarray(res.hops),
-                            ndists=np.asarray(res.ndists), used=used, won=won)
+                with span("catapultdb.engine.rerank"):
+                    rr = jax.vmap(partial(pq_mod.rerank, self._vec, k=k))(
+                        queries, res.ids)
+                    ids, dists = np.asarray(rr[0]), np.asarray(rr[1])
+            stats = SearchStats(hops=np.asarray(res.hops),
+                                ndists=np.asarray(res.ndists),
+                                used=used, won=won)
         return ids, dists, stats
 
     def _dispatch(self, queries: jax.Array, flabels: jax.Array,
@@ -383,7 +390,8 @@ class VectorSearchEngine:
         expansion trace instead of the device-side rerank.  A gated-off
         catapult engine (``catapult_enabled=False``) falls through to
         the diskann dispatch — identical jit cache entry, zero shortcut
-        overhead.
+        overhead.  Nothing here waits for the device: ``used``/``won``
+        are device arrays in catapult mode, which the caller reads back.
         """
         b = queries.shape[0]
         if self.mode == 'catapult' and self.catapult_active:
@@ -396,7 +404,7 @@ class VectorSearchEngine:
                 self._pq if self.pq_subspaces else None,
                 self._codes if self.pq_subspaces else None, pm)
             self._cat = new_cat
-            return res, np.asarray(st.used), np.asarray(st.won)
+            return res, st.used, st.won
         if self.mode == 'lsh_apg':
             res = _search_apg(self._apg, self._adj, self._vec, self._tomb,
                               self._labels, queries, flabels,

@@ -82,7 +82,8 @@ def adc_dist_fn(cb: PQCodebook, codes: jax.Array):
 
     def dist(q: jax.Array, ids: jax.Array) -> jax.Array:
         lut = query_lut(cb, q)                          # (M, K)
-        c = codes[jnp.maximum(ids, 0)]                  # (m_ids, M)
+        with jax.named_scope("hop/gather"):
+            c = codes[jnp.maximum(ids, 0)]              # (m_ids, M)
         g = jnp.take_along_axis(lut[None], c[:, :, None], axis=2)[:, :, 0]
         # subspace by subspace, as the fused PQ hop kernel sums
         d = ordered_sum(g[:, j] for j in range(g.shape[1]))
@@ -93,8 +94,9 @@ def adc_dist_fn(cb: PQCodebook, codes: jax.Array):
 
 def rerank(vectors: jax.Array, q: jax.Array, ids: jax.Array, k: int):
     """Full-precision rerank of the final beam (DiskANN's SSD fetch)."""
-    x = vectors[jnp.maximum(ids, 0)]
-    d = jnp.sum((x - q[None]) ** 2, axis=-1)
-    d = jnp.where(ids < 0, jnp.inf, d)
-    order = jnp.argsort(d)[:k]
-    return ids[order], d[order]
+    with jax.named_scope("rerank"):
+        x = vectors[jnp.maximum(ids, 0)]
+        d = jnp.sum((x - q[None]) ** 2, axis=-1)
+        d = jnp.where(ids < 0, jnp.inf, d)
+        order = jnp.argsort(d)[:k]
+        return ids[order], d[order]

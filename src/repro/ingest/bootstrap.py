@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core.engine import SearchStats
 from repro.db.spec import IndexSpec, IngestSpec
+from repro.obs.trace import stages
 
 
 class _SearchGate:
@@ -279,7 +280,7 @@ class BootstrapEngine:
                 publish_mask=publish_mask, trace=trace)
             ids = np.asarray(ids)
             if trace is not None:
-                with trace.stage("ingest_map"):
+                with trace.stage("ingest_map", "catapultdb.ingest.map"):
                     ids = self._map_ext(ids, int2ext)
                 trace.note(ingest_phase="graph")
             else:
@@ -301,9 +302,7 @@ class BootstrapEngine:
                             used=np.zeros(B, bool),
                             won=np.zeros(B, bool))
         n = self._n_buf
-        span = (trace.stage("bootstrap") if trace is not None
-                else contextlib.nullcontext())
-        with span:
+        with stages(trace)("bootstrap", "catapultdb.ingest.bootstrap"):
             if n:
                 v = self._buf[:n]
                 mask = np.broadcast_to(~self._ext_tomb[:n], (B, n)).copy()

@@ -5,14 +5,6 @@ Pallas kernel (compiled on TPU, ``interpret=True`` elsewhere so CPU CI
 executes the same kernel bodies), and slices the result.  The pure-jnp
 oracles live in ``ref.py``; tests assert op == oracle across shape/dtype
 sweeps.
-
-Profiling: each public op wraps its jit'd dispatch in
-``repro.obs.annotate`` — with ``REPRO_PROFILE=1`` (or
-``repro.obs.enable_profiling()``) a ``jax.profiler`` capture shows
-named host spans per kernel instead of anonymous dispatches.  The
-annotation sits OUTSIDE the jit boundary (a host context manager can't
-live inside a traced function) and is one shared no-op when profiling
-is off.
 """
 from __future__ import annotations
 
@@ -29,7 +21,6 @@ from repro.kernels.l2_distance import l2_distance as _l2_distance
 from repro.kernels.lsh_hash import lsh_hash as _lsh_hash
 from repro.kernels.pq_adc import pq_adc as _pq_adc
 from repro.kernels.platform import interpret_mode
-from repro.obs.profiler import annotate
 
 
 def _pad_rows(x: jax.Array, mult: int, value=0) -> jax.Array:
@@ -56,9 +47,8 @@ def _l2_distance_jit(queries: jax.Array, points: jax.Array, *,
 def l2_distance(queries: jax.Array, points: jax.Array, *,
                 block_q: int = 128, block_c: int = 128) -> jax.Array:
     """(B, d) × (C, d) -> (B, C) squared L2, any B/C (padded internally)."""
-    with annotate("repro.kernels.l2_distance"):
-        return _l2_distance_jit(queries, points, block_q=block_q,
-                                block_c=block_c)
+    return _l2_distance_jit(queries, points, block_q=block_q,
+                            block_c=block_c)
 
 
 @jax.jit
@@ -70,8 +60,7 @@ def _gather_distance_jit(vectors: jax.Array, ids: jax.Array,
 def gather_distance(vectors: jax.Array, ids: jax.Array,
                     query: jax.Array) -> jax.Array:
     """(N, d), (M,) ids, (d,) -> (M,) distances; ids<0 -> +inf."""
-    with annotate("repro.kernels.gather_distance"):
-        return _gather_distance_jit(vectors, ids, query)
+    return _gather_distance_jit(vectors, ids, query)
 
 
 @functools.partial(jax.jit, static_argnames=("block_q",))
@@ -87,8 +76,7 @@ def _lsh_hash_jit(queries: jax.Array, hyperplanes: jax.Array, *,
 def lsh_hash(queries: jax.Array, hyperplanes: jax.Array, *,
              block_q: int = 128) -> jax.Array:
     """(B, d) × (L, d) -> (B,) int32 bucket codes, any B."""
-    with annotate("repro.kernels.lsh_hash"):
-        return _lsh_hash_jit(queries, hyperplanes, block_q=block_q)
+    return _lsh_hash_jit(queries, hyperplanes, block_q=block_q)
 
 
 @functools.partial(jax.jit, static_argnames=("block_c",))
@@ -103,8 +91,7 @@ def _pq_adc_jit(lut: jax.Array, codes: jax.Array, *,
 
 def pq_adc(lut: jax.Array, codes: jax.Array, *, block_c: int = 128) -> jax.Array:
     """(M, K) LUT × (C, M) codes -> (C,) ADC distances, any C."""
-    with annotate("repro.kernels.pq_adc"):
-        return _pq_adc_jit(lut, codes, block_c=block_c)
+    return _pq_adc_jit(lut, codes, block_c=block_c)
 
 
 def fused_hop_l2(vectors, cand_ids, queries, beam_ids, beam_dists, beam_exp):
@@ -114,17 +101,15 @@ def fused_hop_l2(vectors, cand_ids, queries, beam_ids, beam_dists, beam_exp):
     (new_ids, new_dists, new_exp, n_fresh), for any B/C/L (the kernel
     pads the batch to whole lane tiles itself).
     """
-    with annotate("repro.kernels.fused_hop_l2"):
-        return _fused_hop_l2(vectors, cand_ids, queries, beam_ids,
-                             beam_dists, beam_exp, interpret=interpret_mode())
+    return _fused_hop_l2(vectors, cand_ids, queries, beam_ids,
+                         beam_dists, beam_exp, interpret=interpret_mode())
 
 
 def fused_hop_pq(luts, codes, cand_ids, beam_ids, beam_dists, beam_exp):
     """One fused PQ-ADC hop: (B, M, K) LUTs, (N, M) codes, (B, C) ids,
     (B, L) beam -> (new_ids, new_dists, new_exp, n_fresh)."""
-    with annotate("repro.kernels.fused_hop_pq"):
-        return _fused_hop_pq(luts, codes, cand_ids, beam_ids,
-                             beam_dists, beam_exp, interpret=interpret_mode())
+    return _fused_hop_pq(luts, codes, cand_ids, beam_ids,
+                         beam_dists, beam_exp, interpret=interpret_mode())
 
 
 # re-export oracles for convenience in tests/benchmarks

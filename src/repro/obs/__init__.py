@@ -17,16 +17,17 @@ package is the measurement substrate:
                  ``db.search(..., explain=True) -> SearchTrace``.
 * ``window``   — the serving frontend's rolling window (QPS, batch
                  occupancy, flush p99).
-* ``profiler`` — opt-in ``jax.profiler`` annotations around the Pallas
-                 kernels (``REPRO_PROFILE=1`` / ``enable_profiling()``).
+* ``profiler`` — ``span``: host spans (``catapultdb.<layer>.<phase>``)
+                 on the profiler's clock, always on, ~1 µs each with no
+                 capture running; ``profile_trace`` captures them with
+                 the device trace.
 
 See docs/OBSERVABILITY.md for metric names, the trace schema, and a
 Prometheus scrape example.
 """
 from repro.obs.metrics import (DEFAULT_MS_EDGES, Counter, Gauge, Histogram,
                                MetricsRegistry, NULL_INSTRUMENT)
-from repro.obs.profiler import (annotate, enable_profiling, profile_trace,
-                                profiling_enabled)
+from repro.obs.profiler import profile_trace, span
 from repro.obs.trace import (STAGES, SearchTrace, Span, TraceRecorder,
                              build_search_trace)
 from repro.obs.window import RollingWindow
@@ -34,6 +35,5 @@ from repro.obs.window import RollingWindow
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "NULL_INSTRUMENT",
     "DEFAULT_MS_EDGES", "RollingWindow", "STAGES", "SearchTrace", "Span",
-    "TraceRecorder", "build_search_trace", "annotate", "enable_profiling",
-    "profile_trace", "profiling_enabled",
+    "TraceRecorder", "build_search_trace", "profile_trace", "span",
 ]

@@ -1,71 +1,36 @@
-"""Opt-in ``jax.profiler`` annotation hooks for the Pallas kernels.
+"""Host spans on the profiler's clock, and the capture helper.
 
-When profiling is enabled (``REPRO_PROFILE=1`` in the environment, or
-``enable_profiling()`` at runtime), the public kernel entry points in
-``repro.kernels.ops`` wrap each dispatch in a
-``jax.profiler.TraceAnnotation`` — so a ``jax.profiler.trace(...)``
-capture (or a Perfetto/TensorBoard trace) shows named host spans for
-``repro.kernels.l2_distance`` / ``gather_distance`` / ``pq_adc`` /
-``lsh_hash`` instead of anonymous jit dispatches.
+``span(name, **args)`` is the program's one span API: a
+``jax.profiler.TraceAnnotation``.  With no capture running it costs
+about a microsecond and records nothing; under a capture it writes a
+host event onto the profiler's clock, the clock of the device trace, so
+each idle gap of the device lines up with what the host was doing.
+Arguments appear on the event (xprof/Perfetto show them as its
+metadata); ``set_metadata(**args)`` on the returned span adds ones that
+are known only when the span ends.
 
-Disabled (the default), ``annotate`` returns one shared no-op context
-manager: the hot path pays a single truthiness check and no allocation,
-and ``jax`` itself is only imported once profiling actually turns on —
-importing this module never drags the profiler machinery in.
+A span only marks where the host already is: none may wait for the
+device or read a device array.  Names follow
+``catapultdb.<layer>.<phase>``; docs/OBSERVABILITY.md lists them, with
+the device scopes (``jax.named_scope``) inside the search programs.
 """
 from __future__ import annotations
 
-import os
 from contextlib import contextmanager
 
-
-class _NullContext:
-    """Shared reusable no-op context (``contextlib.nullcontext`` is not
-    reusable-by-sharing across threads pre-3.10 idiom; this is)."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return None
-
-    def __exit__(self, *exc):
-        return False
+import jax.profiler
 
 
-_NULL_CONTEXT = _NullContext()
-_enabled = os.environ.get("REPRO_PROFILE", "") not in ("", "0")
-
-
-def profiling_enabled() -> bool:
-    return _enabled
-
-
-def enable_profiling(flag: bool = True) -> None:
-    """Runtime switch (the env var ``REPRO_PROFILE=1`` sets the initial
-    state); affects every subsequent ``annotate`` call."""
-    global _enabled
-    _enabled = bool(flag)
-
-
-def annotate(name: str):
-    """Context manager: a ``jax.profiler.TraceAnnotation(name)`` when
-    profiling is on, the shared no-op otherwise."""
-    if not _enabled:
-        return _NULL_CONTEXT
-    import jax.profiler
-    return jax.profiler.TraceAnnotation(name)
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """A host span ``name`` carrying ``args`` (a context manager)."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 @contextmanager
 def profile_trace(log_dir: str):
-    """Convenience wrapper for a whole capture: everything inside the
-    ``with`` block lands in a ``jax.profiler.trace`` at ``log_dir``
-    (viewable in TensorBoard/Perfetto), with kernel annotations active
-    for the duration."""
-    import jax.profiler
-    was = _enabled
-    enable_profiling(True)
-    try:
-        with jax.profiler.trace(log_dir):
-            yield
-    finally:
-        enable_profiling(was)
+    """Capture everything inside the ``with`` block into a
+    ``jax.profiler.trace`` at ``log_dir`` (viewable in xprof,
+    TensorBoard or Perfetto): the device's operations with their
+    scopes, and the program's spans."""
+    with jax.profiler.trace(log_dir):
+        yield

@@ -22,15 +22,24 @@ entry point chosen per lane, catapult hit/win counts, hops, blocks
 read, and the per-stage wall times.  Tracing costs one device sync per
 stage; it is for debugging and attribution, not the steady-state hot
 path (which reports through ``repro.obs.metrics`` instead).
+
+Each stage runs inside the host span the engine opens there anyway
+(``catapultdb.engine.route``, ``catapultdb.disk.fetch``, ...; see
+``repro.obs.profiler``): ``stages(trace)`` gives a search call its
+``stage(name, span_name)``, which opens the span on every call and also
+times the stage when an explain call passed a recorder.  So an explain
+stage and a profiler capture name the same code the same way.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Optional
 
 import numpy as np
+
+from repro.obs.profiler import span
 
 # stable stage vocabulary — benches and make_report key on these
 STAGES = ("route", "fetch", "rerank", "merge", "scatter")
@@ -58,12 +67,16 @@ class TraceRecorder:
         self.children: list["TraceRecorder"] = []
 
     @contextmanager
-    def stage(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.spans.append(Span(name, (time.perf_counter() - t0) * 1e3))
+    def stage(self, name: str, span_name: Optional[str] = None):
+        """Time stage ``name``, inside the host span ``span_name`` when
+        the caller names the one its code opens there."""
+        with span(span_name) if span_name else nullcontext():
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.spans.append(
+                    Span(name, (time.perf_counter() - t0) * 1e3))
 
     def add_stage(self, name: str, ms: float) -> None:
         self.spans.append(Span(name, float(ms)))
@@ -79,6 +92,17 @@ class TraceRecorder:
     def stage_ms(self, name: str) -> float:
         """Total ms recorded under ``name`` (0.0 if never entered)."""
         return sum(s.ms for s in self.spans if s.name == name)
+
+
+def _span_only(name: str, span_name: str):
+    return span(span_name)
+
+
+def stages(trace: Optional[TraceRecorder]):
+    """``stage(name, span_name)`` for one search call: the host span
+    ``span_name`` always, timed into ``trace`` as stage ``name`` when an
+    explain call passed a recorder."""
+    return trace.stage if trace is not None else _span_only
 
 
 @dataclasses.dataclass

@@ -28,7 +28,7 @@ import numpy as np
 from repro.configs.base import ArchConfig
 from repro.core.engine import SearchStats
 from repro.models import model as M
-from repro.obs import NULL_INSTRUMENT, RollingWindow
+from repro.obs import NULL_INSTRUMENT, RollingWindow, span
 
 
 class VectorSearchFrontend:
@@ -68,6 +68,15 @@ class VectorSearchFrontend:
     optional ``repro.obs.MetricsRegistry``) additionally publishes
     flush counts and a full-history flush-latency histogram;
     ``Database.serve()`` passes its own registry here.
+
+    Each ``flush()``/``search()`` call is one host span
+    (``catapultdb.frontend.flush`` / ``.search``) whose arguments count
+    its ``tickets`` and ``chunks`` and sum and max the waits, in ms,
+    from each ticket's ``submit()`` (a bulk search's call) to the
+    dispatch of its chunk.  Inside it, per chunk, ``pack`` (group,
+    stack, pad, mask), ``dispatch`` (the backend search, argument
+    ``dispatch``: the batch's index), ``unpack`` (trim stats, hand out
+    answers); then ``ingest`` around the ingest pump.
     """
 
     def __init__(self, backend, *, k: int = 10, max_batch: int = 64,
@@ -82,9 +91,11 @@ class VectorSearchFrontend:
         # flush()/bulk search() — writes interleave with serving at
         # flush granularity instead of competing for the backend
         self.ingest = ingest
-        # ticket queue entries: (ticket, query, k, beam_width) with the
-        # per-request overrides already resolved against the defaults
-        self._queue: list[tuple[int, np.ndarray, int, Optional[int]]] = []
+        # ticket queue entries: (ticket, query, k, beam_width, submit
+        # time) with the per-request overrides already resolved against
+        # the defaults
+        self._queue: list[tuple[int, np.ndarray, int, Optional[int],
+                                float]] = []
         self._next_ticket = 0
         self.batches_dispatched = 0
         self.window = RollingWindow()
@@ -101,7 +112,8 @@ class VectorSearchFrontend:
         ticket = self._next_ticket
         self._next_ticket += 1
         self._queue.append((ticket, q, k or self.k,
-                            beam_width or self.beam_width))
+                            beam_width or self.beam_width,
+                            time.perf_counter()))
         return ticket
 
     @property
@@ -112,31 +124,44 @@ class VectorSearchFrontend:
                         beam_width: Optional[int] = None):
         """Pad to the fixed batch shape, search with padded lanes masked
         out of publishes, and return (ids, dists, stats) trimmed to the
-        real lanes; feeds the maintainer when one is attached."""
-        real = qs.shape[0]
-        pad = self.max_batch - real
-        if pad:
-            qs = np.concatenate([qs, np.repeat(qs[-1:], pad, axis=0)])
-        mask = np.zeros(self.max_batch, bool)
-        mask[:real] = True
-        ids, dists, stats = self.backend.search(
-            qs, k=k, beam_width=beam_width, publish_mask=mask)
-        self.batches_dispatched += 1
-        if self.maintainer is not None:
-            # full padded shape + real_mask, NOT the trimmed views: the
-            # telemetry fold is jit'd on array shape, and one fixed
-            # (max_batch,) signature is the whole point of the padding
-            self.maintainer.observe(qs, stats, real_mask=mask)
-        stats = SearchStats(
-            hops=np.asarray(stats.hops)[:real],
-            ndists=np.asarray(stats.ndists)[:real],
-            used=np.asarray(stats.used)[:real],
-            won=np.asarray(stats.won)[:real],
-            block_reads=(None if stats.block_reads is None
-                         else np.asarray(stats.block_reads)[:real]),
-            cache_hits=(None if stats.cache_hits is None
-                        else np.asarray(stats.cache_hits)[:real]))
-        return np.asarray(ids[:real]), np.asarray(dists[:real]), stats
+        real lanes, and when the search was dispatched; feeds the
+        maintainer when one is attached."""
+        with span("catapultdb.frontend.pack"):
+            real = qs.shape[0]
+            pad = self.max_batch - real
+            if pad:
+                qs = np.concatenate([qs, np.repeat(qs[-1:], pad, axis=0)])
+            mask = np.zeros(self.max_batch, bool)
+            mask[:real] = True
+        t_dispatch = time.perf_counter()
+        with span("catapultdb.frontend.dispatch",
+                  dispatch=self.batches_dispatched):
+            ids, dists, stats = self.backend.search(
+                qs, k=k, beam_width=beam_width, publish_mask=mask)
+            self.batches_dispatched += 1
+            if self.maintainer is not None:
+                # full padded shape + real_mask, NOT the trimmed views:
+                # the telemetry fold is jit'd on array shape, and one
+                # fixed (max_batch,) signature is the whole point of the
+                # padding
+                self.maintainer.observe(qs, stats, real_mask=mask)
+        with span("catapultdb.frontend.unpack"):
+            stats = SearchStats(
+                hops=np.asarray(stats.hops)[:real],
+                ndists=np.asarray(stats.ndists)[:real],
+                used=np.asarray(stats.used)[:real],
+                won=np.asarray(stats.won)[:real],
+                block_reads=(None if stats.block_reads is None
+                             else np.asarray(stats.block_reads)[:real]),
+                cache_hits=(None if stats.cache_hits is None
+                            else np.asarray(stats.cache_hits)[:real]))
+            ids, dists = np.asarray(ids[:real]), np.asarray(dists[:real])
+        return ids, dists, stats, t_dispatch
+
+    def _pump_ingest(self) -> None:
+        if self.ingest is not None:
+            with span("catapultdb.frontend.ingest"):
+                self.ingest.pump()
 
     def flush(self) -> dict[int, tuple[np.ndarray, np.ndarray]]:
         """Serve every queued request; returns {ticket: (ids, dists)}.
@@ -145,31 +170,43 @@ class VectorSearchFrontend:
         order is preserved within a pair, and each pair dispatches its
         own fixed-shape chunks, so mixed-k traffic costs one jit
         signature per distinct pair, not one per flush pattern."""
-        out: dict[int, tuple[np.ndarray, np.ndarray]] = {}
-        groups: dict[tuple, list] = {}
-        for entry in self._queue:
-            groups.setdefault((entry[2], entry[3]), []).append(entry)
-        self._queue = []
-        t0 = time.perf_counter()
-        served = 0
-        occupancy: list[float] = []
-        for (k, beam), entries in groups.items():
-            for lo in range(0, len(entries), self.max_batch):
-                chunk = entries[lo: lo + self.max_batch]
-                qs = np.stack([q for _, q, _, _ in chunk])
-                ids, dists, _ = self._dispatch_chunk(qs, k, beam)
-                served += len(chunk)
-                occupancy.append(len(chunk) / self.max_batch)
-                for row, (ticket, _, _, _) in enumerate(chunk):
-                    out[ticket] = (ids[row], dists[row])
-        if served:
-            ms = (time.perf_counter() - t0) * 1e3
-            self.window.record_flush(
-                queries=served, occupancy=float(np.mean(occupancy)), ms=ms)
-            self._m_flushes.inc()
-            self._m_flush_ms.observe(ms)
-        if self.ingest is not None:
-            self.ingest.pump()
+        with span("catapultdb.frontend.flush") as sp:
+            out: dict[int, tuple[np.ndarray, np.ndarray]] = {}
+            with span("catapultdb.frontend.pack"):
+                groups: dict[tuple, list] = {}
+                for entry in self._queue:
+                    groups.setdefault((entry[2], entry[3]), []).append(entry)
+                self._queue = []
+            t0 = time.perf_counter()
+            served = 0
+            occupancy: list[float] = []
+            wait_sum = wait_max = 0.0
+            for (k, beam), entries in groups.items():
+                for lo in range(0, len(entries), self.max_batch):
+                    chunk = entries[lo: lo + self.max_batch]
+                    with span("catapultdb.frontend.pack"):
+                        qs = np.stack([e[1] for e in chunk])
+                    ids, dists, _, t_dispatch = self._dispatch_chunk(
+                        qs, k, beam)
+                    served += len(chunk)
+                    occupancy.append(len(chunk) / self.max_batch)
+                    with span("catapultdb.frontend.unpack"):
+                        for row, entry in enumerate(chunk):
+                            out[entry[0]] = (ids[row], dists[row])
+                    waits = t_dispatch - np.array([e[4] for e in chunk])
+                    wait_sum += float(waits.sum())
+                    wait_max = max(wait_max, float(waits.max()))
+            if served:
+                ms = (time.perf_counter() - t0) * 1e3
+                self.window.record_flush(
+                    queries=served, occupancy=float(np.mean(occupancy)),
+                    ms=ms)
+                self._m_flushes.inc()
+                self._m_flush_ms.observe(ms)
+            self._pump_ingest()
+            sp.set_metadata(tickets=served, chunks=len(occupancy),
+                            wait_ms_sum=wait_sum * 1e3,
+                            wait_ms_max=wait_max * 1e3)
         return out
 
     def search(self, queries: np.ndarray, k: Optional[int] = None,
@@ -182,23 +219,31 @@ class VectorSearchFrontend:
         if queries.shape[0] == 0:
             return (np.empty((0, k), np.int32),
                     np.empty((0, k), np.float32), [])
-        all_ids, all_d, all_stats = [], [], []
-        t0 = time.perf_counter()
-        occupancy: list[float] = []
-        for lo in range(0, queries.shape[0], self.max_batch):
-            ids, dists, stats = self._dispatch_chunk(
-                queries[lo: lo + self.max_batch], k, beam_width)
-            occupancy.append(ids.shape[0] / self.max_batch)
-            all_ids.append(ids)
-            all_d.append(dists)
-            all_stats.append(stats)
-        ms = (time.perf_counter() - t0) * 1e3
-        self.window.record_flush(queries=int(queries.shape[0]),
-                                 occupancy=float(np.mean(occupancy)), ms=ms)
-        self._m_flushes.inc()
-        self._m_flush_ms.observe(ms)
-        if self.ingest is not None:
-            self.ingest.pump()
+        with span("catapultdb.frontend.search") as sp:
+            all_ids, all_d, all_stats = [], [], []
+            t0 = time.perf_counter()
+            occupancy: list[float] = []
+            wait_sum = wait_max = 0.0
+            for lo in range(0, queries.shape[0], self.max_batch):
+                ids, dists, stats, t_dispatch = self._dispatch_chunk(
+                    queries[lo: lo + self.max_batch], k, beam_width)
+                occupancy.append(ids.shape[0] / self.max_batch)
+                all_ids.append(ids)
+                all_d.append(dists)
+                all_stats.append(stats)
+                wait_sum += (t_dispatch - t0) * ids.shape[0]
+                wait_max = t_dispatch - t0
+            ms = (time.perf_counter() - t0) * 1e3
+            self.window.record_flush(queries=int(queries.shape[0]),
+                                     occupancy=float(np.mean(occupancy)),
+                                     ms=ms)
+            self._m_flushes.inc()
+            self._m_flush_ms.observe(ms)
+            self._pump_ingest()
+            sp.set_metadata(tickets=int(queries.shape[0]),
+                            chunks=len(occupancy),
+                            wait_ms_sum=wait_sum * 1e3,
+                            wait_ms_max=wait_max * 1e3)
         return (np.concatenate(all_ids), np.concatenate(all_d), all_stats)
 
 

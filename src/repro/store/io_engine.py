@@ -36,7 +36,6 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from contextlib import nullcontext
 from typing import Optional
 
 import jax.numpy as jnp
@@ -48,6 +47,8 @@ from repro.core import catapult as cat
 from repro.core.beam_search import SearchSpec
 from repro.core.engine import DiskStore, SearchStats, VectorSearchEngine
 from repro.db.spec import IoSpec
+from repro.obs.profiler import span
+from repro.obs.trace import stages
 from repro.store.cache import IoStats, NodeCache
 from repro.store.layout import open_store
 from repro.store.pipeline import IoPipeline
@@ -321,9 +322,10 @@ class DiskVectorSearchEngine(VectorSearchEngine):
         route/fetch/rerank stages for the ``explain`` search mode.
         """
         q_np = np.ascontiguousarray(queries, np.float32)
-        queries_j = jnp.asarray(q_np)
+        with span("catapultdb.engine.upload"):
+            queries_j = jnp.asarray(q_np)
         b = queries_j.shape[0]
-        stage = trace.stage if trace is not None else (lambda _: nullcontext())
+        stage = stages(trace)
         # Wider default beam than the RAM engine (L ≈ 3k, not 2k): the
         # traversal is steered by PQ-approximate distances, and the slack
         # keeps true neighbors in the frontier despite quantization noise —
@@ -336,11 +338,15 @@ class DiskVectorSearchEngine(VectorSearchEngine):
                    if filter_labels is not None
                    else jnp.full((b,), -1, jnp.int32))
 
-        with stage("route"):
+        # explain's "route" stage ends once the beams are on the host
+        with stage("route", "catapultdb.engine.route"):
             res, used, won = self._dispatch(queries_j, flabels, spec,
                                             publish_mask=publish_mask)
-            beam_ids = np.asarray(res.ids)      # (B, l), tombstones masked
-            expansions = np.asarray(res.trace)  # (B, max_iters), -1 padded
+            with span("catapultdb.engine.readback"):
+                used, won = np.asarray(used), np.asarray(won)
+                beam_ids = np.asarray(res.ids)      # (B, l), tombstones masked
+                expansions = np.asarray(res.trace)  # (B, max_iters), -1 padded
+                hops, ndists = np.asarray(res.hops), np.asarray(res.ndists)
         fl_np = (np.asarray(filter_labels, np.int32)
                  if filter_labels is not None else None)
 
@@ -348,38 +354,42 @@ class DiskVectorSearchEngine(VectorSearchEngine):
         out_d = np.full((b, k), np.inf, np.float32)
         block_reads = np.zeros(b, np.int32)
         cache_hits = np.zeros(b, np.int32)
-        # DiskANN's per-query I/O: a block per expansion (the adjacency
-        # row lives in it) plus the unexpanded beam tail for rerank.
-        wants = []
-        for lane in range(b):
-            beam = beam_ids[lane]
-            expanded = expansions[lane]
-            want = np.concatenate([expanded[expanded >= 0],
-                                   beam[beam >= 0]])
-            wants.append(np.unique(want))
-        # One deduplicated multi-node fetch for the whole beam round:
-        # lanes that landed on the same hot blocks share a single load
-        # (batched_reads counts the deduplicated I/O; a node's miss is
-        # charged to the first lane that wanted it).
-        if self._pipeline is not None:
-            # new beam round: last round's still-queued speculation is a
-            # misprediction now — cancel it before it costs a read
-            self._pipeline.advance()
-            # submission phase, demand half: every block this round's
-            # rerank needs, deduplicated across lanes, goes to the
-            # worker pool NOW — fetch_batch below then COMPLETES against
-            # in-flight reads instead of paying each miss serially
-            self._pipeline.submit(np.unique(np.concatenate(wants)))
-        with stage("fetch"):
+        with span("catapultdb.disk.plan"):
+            # DiskANN's per-query I/O: a block per expansion (the
+            # adjacency row lives in it) plus the unexpanded beam tail
+            # for rerank.
+            wants = []
+            for lane in range(b):
+                beam = beam_ids[lane]
+                expanded = expansions[lane]
+                want = np.concatenate([expanded[expanded >= 0],
+                                       beam[beam >= 0]])
+                wants.append(np.unique(want))
+            # One deduplicated multi-node fetch for the whole beam round:
+            # lanes that landed on the same hot blocks share a single
+            # load (batched_reads counts the deduplicated I/O; a node's
+            # miss is charged to the first lane that wanted it).
+            if self._pipeline is not None:
+                # new beam round: last round's still-queued speculation
+                # is a misprediction now — cancel it before it costs a
+                # read
+                self._pipeline.advance()
+                # submission phase, demand half: every block this
+                # round's rerank needs, deduplicated across lanes, goes
+                # to the worker pool NOW — fetch_batch below then
+                # COMPLETES against in-flight reads instead of paying
+                # each miss serially
+                self._pipeline.submit(np.unique(np.concatenate(wants)))
+        with stage("fetch", "catapultdb.disk.fetch"):
             fetched = self._cache.fetch_batch(wants)
         if self._pipeline is not None:
             # submission phase: queue the beam frontier's neighborhoods
             # before reranking, so the speculative reads complete in the
             # background while the host computes full-precision distances
             # (and while the device routes the next batch)
-            with stage("speculate"):
+            with stage("speculate", "catapultdb.disk.speculate"):
                 self._speculate(beam_ids, wants, fetched)
-        with stage("rerank"):
+        with stage("rerank", "catapultdb.disk.rerank"):
             for lane, (want, (vecs, _, hits, misses)) in enumerate(
                     zip(wants, fetched)):
                 cache_hits[lane], block_reads[lane] = hits, misses
@@ -408,12 +418,11 @@ class DiskVectorSearchEngine(VectorSearchEngine):
                 and self.pin_catapult_destinations:
             # the freshly published destinations (best neighbor per query)
             # are the likeliest next landing blocks — soft-pin them
-            dests = out_ids[:, 0]
-            self._cache.pin_rotating(np.unique(dests[dests >= 0]))
+            with span("catapultdb.disk.pin"):
+                dests = out_ids[:, 0]
+                self._cache.pin_rotating(np.unique(dests[dests >= 0]))
 
-        stats = SearchStats(hops=np.asarray(res.hops),
-                            ndists=np.asarray(res.ndists),
-                            used=used, won=won,
+        stats = SearchStats(hops=hops, ndists=ndists, used=used, won=won,
                             block_reads=block_reads, cache_hits=cache_hits)
         return out_ids, out_d, stats
 

@@ -46,7 +46,6 @@ import dataclasses
 import json
 import os
 from concurrent.futures import ThreadPoolExecutor
-from contextlib import nullcontext
 from typing import Optional
 
 import jax.numpy as jnp
@@ -59,6 +58,7 @@ from repro.core.engine import SearchStats
 from repro.core.sharded import merge_topk, rebase_ids
 from repro.core.vamana import VamanaParams
 from repro.db.spec import IoSpec
+from repro.obs.trace import stages
 from repro.store.cache import CacheStats, IoStats
 from repro.store.io_engine import DiskVectorSearchEngine
 
@@ -265,7 +265,7 @@ class ShardedDiskVectorSearchEngine:
         """
         if not self.shards:
             raise RuntimeError("build() or load() first")
-        stage = trace.stage if trace is not None else (lambda _: nullcontext())
+        stage = stages(trace)
         # mirror the single-store default (L ≈ 3k, io_engine.search),
         # then divide it over the scatter width
         beam = beam_width or max(3 * k, 24)
@@ -280,9 +280,9 @@ class ShardedDiskVectorSearchEngine:
                               max_iters=max_iters,
                               publish_mask=publish_mask, trace=kid)
 
-        with stage("scatter"):
+        with stage("scatter", "catapultdb.sharded.scatter"):
             results = list(self._executor().map(one, zip(self.shards, kids)))
-        with stage("merge"):
+        with stage("merge", "catapultdb.sharded.merge"):
             all_ids = np.stack([
                 np.asarray(rebase_ids(ids, int(self.offsets[s])))
                 for s, (ids, _, _) in enumerate(results)])        # (S, B, k)

@@ -59,7 +59,6 @@ import dataclasses
 import json
 import os
 from concurrent.futures import ThreadPoolExecutor
-from contextlib import nullcontext
 from typing import Optional
 
 import jax.numpy as jnp
@@ -70,6 +69,7 @@ from repro.core.engine import SearchStats, VectorSearchEngine
 from repro.core.sharded import merge_topk
 from repro.core.vamana import VamanaParams
 from repro.db.spec import IoSpec, TieredSpec
+from repro.obs.trace import stages
 from repro.store.cache import CacheStats, IoStats
 
 TIERED_MANIFEST_NAME = "tiered.json"
@@ -386,14 +386,14 @@ class TieredVectorSearchEngine:
             raise RuntimeError("build() or load() first")
         q_np = np.ascontiguousarray(queries, np.float32)
         b = q_np.shape[0]
-        stage = trace.stage if trace is not None else (lambda _: nullcontext())
+        stage = stages(trace)
         beam = beam_width or max(3 * k, 24)
         fl_np = (np.asarray(filter_labels, np.int32)
                  if filter_labels is not None else None)
         hot_kid = trace.child("hot") if trace is not None else None
         cold_kid = trace.child("cold") if trace is not None else None
 
-        with stage("scatter"):
+        with stage("scatter", "catapultdb.tiered.scatter"):
             fut = self._executor().submit(
                 self._search_hot, q_np, k, beam, fl_np, hot_kid)
             cold_ids, cold_d, cold_st = self.cold.search(
@@ -401,7 +401,7 @@ class TieredVectorSearchEngine:
                 max_iters=max_iters, publish_mask=publish_mask,
                 trace=cold_kid)
             hot_ids, hot_d, hot_st = fut.result()
-        with stage("merge"):
+        with stage("merge", "catapultdb.tiered.merge"):
             all_ids = np.stack([hot_ids,
                                 np.asarray(cold_ids, np.int64)])  # (2, B, k)
             all_d = np.stack([hot_d, np.asarray(cold_d, np.float32)])
