@@ -54,6 +54,9 @@ class CatapultStats(NamedTuple):
     won: jax.Array    # (B,) bool — best start was a catapult, not the medoid
     hops: jax.Array
     ndists: jax.Array
+    lanes_published: jax.Array   # () int32 lanes that published a destination
+    publish_rounds: jax.Array    # () int32 rounds the publish ran: the
+                                 # busiest bucket's publishing lanes
 
 
 def catapulted_lookup(
@@ -126,6 +129,8 @@ def catapulted_lookup(
         won &= pm
     new_buckets = bk.publish(state.buckets, hashes, best, flt)
     new_state = CatapultState(lsh=state.lsh, buckets=new_buckets)
-    stats = CatapultStats(used=used, won=won, hops=result.hops,
-                          ndists=result.ndists)
+    stats = CatapultStats(
+        used=used, won=won, hops=result.hops, ndists=result.ndists,
+        lanes_published=jnp.sum(best >= 0, dtype=jnp.int32),
+        publish_rounds=bk.publish_rounds(state.buckets, hashes, best))
     return new_state, result, stats

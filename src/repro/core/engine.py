@@ -53,6 +53,19 @@ class SearchStats(NamedTuple):
     # disk-backed engines only (None on the RAM path):
     block_reads: Optional[np.ndarray] = None   # (B,) node blocks read from disk
     cache_hits: Optional[np.ndarray] = None    # (B,) node cache hits
+    # catapult mode under a trace (explain) only: elsewhere the read
+    # back would cost every dispatch a device->host round trip
+    lanes_published: Optional[int] = None      # lanes that published
+    publish_rounds: Optional[int] = None       # rounds the publish ran
+
+
+def publish_counts(pub, trace) -> dict:
+    """``SearchStats`` keywords for the publish counts that ``_dispatch``
+    returned: read back under a trace (explain) only, else none."""
+    if trace is None or pub is None:
+        return {}
+    lanes, rounds = jax.device_get(pub)
+    return dict(lanes_published=int(lanes), publish_rounds=int(rounds))
 
 
 # ---------------------------------------------------------------------------
@@ -361,8 +374,8 @@ class VectorSearchEngine:
 
         stage = stages(trace)
         with stage("route", "catapultdb.engine.route"):
-            res, used, won = self._dispatch(queries, flabels, spec,
-                                            publish_mask=publish_mask)
+            res, used, won, pub = self._dispatch(queries, flabels, spec,
+                                                 publish_mask=publish_mask)
             if trace is not None:
                 jax.block_until_ready(res.ids)
 
@@ -378,12 +391,14 @@ class VectorSearchEngine:
                     ids, dists = np.asarray(rr[0]), np.asarray(rr[1])
             stats = SearchStats(hops=np.asarray(res.hops),
                                 ndists=np.asarray(res.ndists),
-                                used=used, won=won)
+                                used=used, won=won,
+                                **publish_counts(pub, trace))
         return ids, dists, stats
 
     def _dispatch(self, queries: jax.Array, flabels: jax.Array,
                   spec: 'SearchSpec', publish_mask=None):
-        """Run the mode's jit'd traversal; returns (raw result, used, won).
+        """Run the mode's jit'd traversal; returns (raw result, used, won,
+        publish counts).
 
         Shared by the RAM search above and the disk engine's I/O-counted
         rerank path (repro.store.io_engine), which consumes the raw
@@ -391,7 +406,10 @@ class VectorSearchEngine:
         catapult engine (``catapult_enabled=False``) falls through to
         the diskann dispatch — identical jit cache entry, zero shortcut
         overhead.  Nothing here waits for the device: ``used``/``won``
-        are device arrays in catapult mode, which the caller reads back.
+        are device arrays in catapult mode, which the caller reads back,
+        and the publish counts the device scalars
+        ``(lanes_published, publish_rounds)`` (None in other modes),
+        which only ``publish_counts`` under a trace reads.
         """
         b = queries.shape[0]
         if self.mode == 'catapult' and self.catapult_active:
@@ -404,7 +422,8 @@ class VectorSearchEngine:
                 self._pq if self.pq_subspaces else None,
                 self._codes if self.pq_subspaces else None, pm)
             self._cat = new_cat
-            return res, st.used, st.won
+            return res, st.used, st.won, (st.lanes_published,
+                                          st.publish_rounds)
         if self.mode == 'lsh_apg':
             res = _search_apg(self._apg, self._adj, self._vec, self._tomb,
                               self._labels, queries, flabels,
@@ -417,7 +436,7 @@ class VectorSearchEngine:
                                   self._pq if self.pq_subspaces else None,
                                   self._codes if self.pq_subspaces else None)
         z = np.zeros(b, bool)
-        return res, z, z
+        return res, z, z, None
 
     def search_two_phase(self, queries: np.ndarray, k: int,
                          beam_width: int | None = None,

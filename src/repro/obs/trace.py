@@ -110,11 +110,16 @@ class SearchTrace:
     """The ``explain=True`` return: the answer plus how it was found.
 
     ``ids``/``dists``/``stats`` are exactly what the non-explain call
-    returns.  ``entry`` is the per-lane entry point actually taken:
+    returns, but for the publish counts ``stats`` carries under explain
+    alone.  ``entry`` is the per-lane entry point actually taken:
     ``'catapult'`` (the bucket supplied a valid destination),
     ``'label_entry'`` (filtered lane falling back to its per-label
     entry point), or ``'medoid'``.  ``catapult_won`` counts lanes whose
-    best start beat the fallback.  ``stages`` are wall-time spans (see
+    best start beat the fallback.  ``lanes_published`` counts lanes
+    that published a destination to their bucket, and
+    ``publish_rounds`` the rounds that publish ran: its busiest
+    bucket's publishing lanes (both None without a catapult layer;
+    summed over shards).  ``stages`` are wall-time spans (see
     module docstring for the vocabulary); on the sharded tier
     ``route``/``fetch``/``rerank`` are critical-path maxima over the
     overlapped shards and ``shards`` holds each shard's own spans.
@@ -130,6 +135,8 @@ class SearchTrace:
     entry: np.ndarray             # (B,) unicode: catapult|label_entry|medoid
     catapult_used: int            # lanes whose bucket supplied a start
     catapult_won: int             # lanes whose catapult start beat fallback
+    lanes_published: Optional[int]   # lanes that published a destination
+    publish_rounds: Optional[int]    # rounds the bucket publish ran
     hops: np.ndarray              # (B,)
     blocks_read: Optional[np.ndarray]    # (B,) — disk tiers only
     cache_hits: Optional[np.ndarray]     # (B,)
@@ -149,6 +156,8 @@ class SearchTrace:
                              for kind in np.unique(self.entry)},
             "catapult_used": self.catapult_used,
             "catapult_won": self.catapult_won,
+            "lanes_published": self.lanes_published,
+            "publish_rounds": self.publish_rounds,
             "hops_mean": float(np.mean(self.hops)),
             "blocks_read_mean": (None if self.blocks_read is None
                                  else float(np.mean(self.blocks_read))),
@@ -181,6 +190,8 @@ def build_search_trace(*, ids, dists, stats, tier: str, mode: str, k: int,
         tier=tier, mode=mode, batch=b, k=k, beam_width=beam_width,
         entry=entry, catapult_used=int(used.sum()),
         catapult_won=int(won.sum()),
+        lanes_published=stats.lanes_published,
+        publish_rounds=stats.publish_rounds,
         hops=np.asarray(stats.hops),
         blocks_read=(None if stats.block_reads is None
                      else np.asarray(stats.block_reads)),

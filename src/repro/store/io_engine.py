@@ -45,7 +45,8 @@ from repro.adapt import stats as adapt_stats
 from repro.core import buckets as bk
 from repro.core import catapult as cat
 from repro.core.beam_search import SearchSpec
-from repro.core.engine import DiskStore, SearchStats, VectorSearchEngine
+from repro.core.engine import (DiskStore, SearchStats, VectorSearchEngine,
+                               publish_counts)
 from repro.db.spec import IoSpec
 from repro.obs.profiler import span
 from repro.obs.trace import stages
@@ -340,13 +341,14 @@ class DiskVectorSearchEngine(VectorSearchEngine):
 
         # explain's "route" stage ends once the beams are on the host
         with stage("route", "catapultdb.engine.route"):
-            res, used, won = self._dispatch(queries_j, flabels, spec,
-                                            publish_mask=publish_mask)
+            res, used, won, pub = self._dispatch(queries_j, flabels, spec,
+                                                 publish_mask=publish_mask)
             with span("catapultdb.engine.readback"):
                 used, won = np.asarray(used), np.asarray(won)
                 beam_ids = np.asarray(res.ids)      # (B, l), tombstones masked
                 expansions = np.asarray(res.trace)  # (B, max_iters), -1 padded
                 hops, ndists = np.asarray(res.hops), np.asarray(res.ndists)
+                counts = publish_counts(pub, trace)
         fl_np = (np.asarray(filter_labels, np.int32)
                  if filter_labels is not None else None)
 
@@ -423,7 +425,8 @@ class DiskVectorSearchEngine(VectorSearchEngine):
                 self._cache.pin_rotating(np.unique(dests[dests >= 0]))
 
         stats = SearchStats(hops=hops, ndists=ndists, used=used, won=won,
-                            block_reads=block_reads, cache_hits=cache_hits)
+                            block_reads=block_reads, cache_hits=cache_hits,
+                            **counts)
         return out_ids, out_d, stats
 
     def _speculate(self, beam_ids: np.ndarray, wants, fetched) -> None:

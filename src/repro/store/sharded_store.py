@@ -75,6 +75,16 @@ def _bucket_file(s: int) -> str:
     return f"shard_{s:04d}.buckets.npz"
 
 
+def _summed_publish_counts(stats: list[SearchStats]) -> dict:
+    """The shards' explain-only publish counts, summed; none when the
+    shards reported none (no trace, or no catapult layer)."""
+    counted = [st for st in stats if st.publish_rounds is not None]
+    if not counted:
+        return {}
+    return dict(lanes_published=sum(st.lanes_published for st in counted),
+                publish_rounds=sum(st.publish_rounds for st in counted))
+
+
 @dataclasses.dataclass
 class ShardedDiskVectorSearchEngine:
     """Scatter-gather facade over S disk-resident shard engines."""
@@ -249,7 +259,9 @@ class ShardedDiskVectorSearchEngine:
         block reads stay in the single-store regime instead of
         multiplying by S.  Per-lane stats aggregate over shards:
         hops/ndists/block_reads/cache_hits sum (total work the query
-        cost the system), used/won OR (any shard's catapult fired).
+        cost the system), used/won OR (any shard's catapult fired); the
+        explain-only publish counts sum (every shard publishes into its
+        own buckets).
 
         Filtered queries (``filter_labels``, -1 = unfiltered lane) fan
         out unchanged: every shard constrains its own traversal via its
@@ -303,7 +315,8 @@ class ShardedDiskVectorSearchEngine:
             block_reads=np.sum([st.block_reads for _, _, st in results],
                                axis=0),
             cache_hits=np.sum([st.cache_hits for _, _, st in results],
-                              axis=0))
+                              axis=0),
+            **_summed_publish_counts([st for _, _, st in results]))
         return merged_ids, merged_d, stats
 
     # ---------------------------------------------------------------- updates

@@ -372,8 +372,9 @@ class TieredVectorSearchEngine:
         beam (so tiered recall can never fall below pure-disk recall);
         the hot tier adds its full-precision candidates on top.  Both
         run concurrently on the thread pool.  Per-lane stats: hops and
-        ndists sum over tiers (total work), used/won come from the cold
-        tier (the only one with a catapult layer), block_reads and
+        ndists sum over tiers (total work), used/won and the publish
+        counts come from the cold tier (the only one with a catapult
+        layer), block_reads and
         cache_hits are the cold tier's (the hot tier does no block I/O
         — that is the whole point).
 
@@ -435,7 +436,9 @@ class TieredVectorSearchEngine:
             hops=np.asarray(cold_st.hops) + np.asarray(hot_st.hops),
             ndists=np.asarray(cold_st.ndists) + np.asarray(hot_st.ndists),
             used=np.asarray(cold_st.used), won=np.asarray(cold_st.won),
-            block_reads=cold_st.block_reads, cache_hits=cold_st.cache_hits)
+            block_reads=cold_st.block_reads, cache_hits=cold_st.cache_hits,
+            lanes_published=cold_st.lanes_published,
+            publish_rounds=cold_st.publish_rounds)
         return out_ids, out_d, stats
 
     # ---------------------------------------------------------------- updates

@@ -129,3 +129,17 @@ def test_beam_search_compiles_and_fits(chip, hop_backend):
     total = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     assert total <= HBM_BYTES, (hop_backend, total)
+
+
+def test_bucket_publish_compiles_without_scatter(chip):
+    """The catapult publish at 4096 lanes over the (256, 40) tables: a
+    vmapped scatter lost writes at 4096 lanes on the chip, so the
+    compiled publish must hold none."""
+    from repro.core import buckets as bk
+    table = chip((2 ** LSH_BITS, STARTS - 1), i32)
+    state = bk.BucketState(ids=table, stamp=table, tag=table,
+                           step=chip((), i32))
+    lanes = chip((B,), i32)
+    text = bk.publish.lower(state, lanes, lanes, lanes).compile().as_text()
+    # instructions only: the metadata's stack frames name this test
+    assert " while(" in text and " scatter(" not in text

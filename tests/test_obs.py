@@ -183,6 +183,38 @@ class TestExplain:
             assert tr.stage_ms(name) == pytest.approx(max(per_shard))
         db.close()
 
+    @pytest.mark.parametrize("tier", ["ram", "disk"])
+    def test_publish_rounds_count_the_busiest_bucket(self, data, queries,
+                                                     tier, tmp_path):
+        """``publish_rounds`` is the busiest bucket's publishing lanes,
+        counted here from the same hashes; masked lanes (the frontend's
+        padding, ``publish=False``) count in no bucket."""
+        from repro.core import lsh as lsh_mod
+        spec = dataclasses.replace(SPEC, tier=tier,
+                                   path=str(tmp_path / "p.ctpl"))
+        db = catapultdb.create(spec, data)
+        q = np.concatenate([queries, queries[:5], queries[:2]])
+        hashes = np.asarray(lsh_mod.hash_codes(db.backend._cat.lsh, q))
+
+        def want(mask):
+            return int(np.bincount(hashes[mask]).max(initial=0))
+
+        everyone = np.ones(q.shape[0], bool)
+        d = db.search(q, k=5, explain=True).to_dict()
+        assert d["lanes_published"] == q.shape[0]
+        assert d["publish_rounds"] == want(everyone) >= 3
+        d = db.search(q, k=5, publish=False, explain=True).to_dict()
+        assert d["lanes_published"] == d["publish_rounds"] == 0
+        padded = everyone.copy()
+        padded[:2] = padded[-4:] = False
+        _, _, st = db.backend.search(q, k=5, publish_mask=padded,
+                                     trace=TraceRecorder())
+        assert st.lanes_published == padded.sum()
+        assert st.publish_rounds == want(padded) < want(everyone)
+        # the served path does not read the counts back
+        assert db.search(q, k=5).stats.publish_rounds is None
+        db.close()
+
     def test_trace_to_dict_is_json_ready(self, data, queries):
         db = catapultdb.create(SPEC, data)
         tr = db.search(queries, k=3, publish=False, explain=True)

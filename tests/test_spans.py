@@ -164,6 +164,7 @@ def test_explain_keys_unchanged(db, queries):
     d = tr.to_dict()
     assert list(d) == ["tier", "mode", "batch", "k", "beam_width",
                        "entry_counts", "catapult_used", "catapult_won",
+                       "lanes_published", "publish_rounds",
                        "hops_mean", "blocks_read_mean", "stages_ms",
                        "shards", "total_ms"]
     want = ({"route", "fetch", "rerank"} if db.spec.tier == "disk"
